@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import core
+from repro.compile_cache import enable_compile_cache
 from repro.core import estimator as est
 
 _REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
@@ -965,15 +966,20 @@ BENCHES = [
 ]
 
 
-def run_paper_suite(key) -> None:
+def run_paper_suite(key) -> int:
+    """Run every paper row; a row that raises prints an ``ERROR`` row and
+    the rest still run. Returns the number of rows that errored."""
     print("name,us_per_call,derived,notes")
+    errors = 0
     for name, fn in BENCHES:
         try:
             us, derived, notes = fn(jax.random.fold_in(
                 key, zlib.crc32(name.encode()) % 2**31))
             print(f"{name},{us:.0f},{derived:.4f},{notes}", flush=True)
         except Exception as e:   # noqa: BLE001
+            errors += 1
             print(f"{name},nan,nan,ERROR {e}", flush=True)
+    return errors
 
 
 def run_estimation_suite(key, out_path: str, smoke: bool) -> None:
@@ -1122,9 +1128,11 @@ def main() -> None:
     p.add_argument("--out-ingest", default="BENCH_ingest.json",
                    help="JSON artifact path for the multi-host ingest suite")
     args = p.parse_args()
+    enable_compile_cache()
     key = jax.random.PRNGKey(0)
+    errors = 0
     if args.suite in ("paper", "all"):
-        run_paper_suite(key)
+        errors += run_paper_suite(key)
     if args.suite in ("estimation", "all"):
         run_estimation_suite(key, args.out, args.smoke)
     if args.suite in ("streaming", "all"):
@@ -1139,6 +1147,8 @@ def main() -> None:
         run_kernels_suite(key, args.out_kernels, args.smoke)
     if args.suite in ("ingest", "all"):
         run_ingest_suite(key, args.out_ingest, args.smoke)
+    if errors:
+        raise SystemExit(f"{errors} benchmark row(s) errored")
 
 
 if __name__ == "__main__":

@@ -17,8 +17,9 @@ tests/core/test_summary_engine.py):
 * ``rows``        — arbitrary-order row streams: rows arrive as
       (global index, A row, B row) chunks in any order.
 * ``pallas``      — fused TPU kernels (sketch + norms in one HBM pass;
-      SRHT via the blocked-FWHT MXU kernel). Fastest on accelerators;
-      runs interpreted on CPU so the same code path is CI-tested.
+      SRHT via the blocked-FWHT MXU kernel). Compiled by Mosaic on a TPU
+      (``python chip_smoke.py`` runs it there); interpreted on the CPU,
+      which checks the kernel bodies but not what the TPU compiler takes.
 * ``distributed`` — rows sharded over a mesh axis (pass mesh=/axis=);
       one psum aggregates the shards (Spark treeAggregate as collectives).
 

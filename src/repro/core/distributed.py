@@ -19,10 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # newer jax promoted it to the top level
-    from jax import shard_map
+from jax import shard_map
 
 from repro.core.types import LowRankFactors, SketchSummary
 
@@ -109,7 +106,7 @@ def distributed_sketch_summary(mesh: Mesh, axis, key: jax.Array,
     ``d``, so the summary is bit-identical to passing pre-padded inputs.
     """
     from repro.core.summary_engine import (
-        _cast, pi_rows, srht_plan, srht_rows_from_plan)
+        _cast, _sketch_dot, pi_rows, srht_plan, srht_rows_from_plan)
     axes = _reduce_axes(mesh, axis)
     n_shards = _axes_size(mesh, axes)
     d = A.shape[0]
@@ -135,12 +132,8 @@ def distributed_sketch_summary(mesh: Mesh, axis, key: jax.Array,
                                         srows, gids, k)
         Ac = _cast(A_loc, precision)
         Bc = _cast(B_loc, precision)
-        dot = lambda X: jax.lax.dot_general(
-            _cast(P_loc, precision).astype(X.dtype), X,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        As = _block_psum(dot(Ac), axes)
-        Bs = _block_psum(dot(Bc), axes)
+        As = _block_psum(_sketch_dot(P_loc, Ac, precision), axes)
+        Bs = _block_psum(_sketch_dot(P_loc, Bc, precision), axes)
         na2 = _scalar_psum(jnp.sum(Ac.astype(jnp.float32) ** 2, axis=0), axes)
         nb2 = _scalar_psum(jnp.sum(Bc.astype(jnp.float32) ** 2, axis=0), axes)
         return SketchSummary(As, Bs, jnp.sqrt(na2), jnp.sqrt(nb2))

@@ -53,6 +53,10 @@ _EPS = 1e-12
 # 97.5% normal quantile: the default two-sided 95% confidence interval
 _Z95 = 1.959964
 
+# f32 products stay f32 on a TPU (its default matmul rounds inputs to bf16,
+# which the residual's cancellation against the probe block cannot absorb)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 # ---------------------------------------------------------------------------
 # The probe block (single-pass accumulation primitives)
@@ -84,9 +88,11 @@ def probe_contribution(omega: jax.Array, A_chunk: jax.Array,
     Ac, Bc = _cast(A_chunk, precision), _cast(B_chunk, precision)
     Bw = jax.lax.dot_general(Bc, _cast(omega, precision).astype(Bc.dtype),
                              dimension_numbers=(((1,), (0,)), ((), ())),
+                             precision=_HIGHEST,
                              preferred_element_type=jnp.float32)
     return jax.lax.dot_general(Ac, Bw.astype(Ac.dtype),
                                dimension_numbers=(((0,), (0,)), ((), ())),
+                               precision=_HIGHEST,
                                preferred_element_type=jnp.float32)
 
 
@@ -178,7 +184,9 @@ def estimate_error(summary: SketchSummary, factors: LowRankFactors, *,
     _require_probes(summary)
     probes, omega = summary.probes, summary.probe_omega
     p = probes.shape[-1]
-    resid = probes - factors.U @ (factors.V.T @ omega)        # (n1, p)
+    resid = probes - jnp.dot(factors.U, jnp.dot(factors.V.T, omega,
+                                                precision=_HIGHEST),
+                             precision=_HIGHEST)              # (n1, p)
     sq = jnp.sum(resid.astype(jnp.float32) ** 2, axis=0)      # (p,) unbiased
     frob_sq = jnp.mean(sq)
     # normal-approximation CI over the p probe samples (sample std, ddof=1;
@@ -268,8 +276,8 @@ def _rank_curve(summary: SketchSummary, r_max: int, refine=None):
         M = estimator.rescaled_matrix(summary).astype(jnp.float32)
         U, s, Vt = jnp.linalg.svd(M, full_matrices=False)
         U, s, Vt = U[:, :r_max], s[:r_max], Vt[:r_max]
-    c = U.T @ probes                                   # (r_max, p)
-    Z = s[:, None] * (Vt @ omega)                      # (r_max, p)
+    c = jnp.dot(U.T, probes, precision=_HIGHEST)       # (r_max, p)
+    Z = s[:, None] * jnp.dot(Vt, omega, precision=_HIGHEST)   # (r_max, p)
     base = jnp.sum(probes ** 2, axis=0)                # (p,)
     deltas = Z ** 2 - 2.0 * c * Z                      # (r_max, p)
     errsq = jnp.maximum(base[None, :] + jnp.cumsum(deltas, axis=0), 0.0)

@@ -15,13 +15,31 @@ from repro.core.types import SketchSummary
 
 _EPS = 1e-12
 
+#: samples gathered per step: the (k, m) gathers of the whole paper-default
+#: sample would take 2 * 4 * k * m bytes (13 GiB at n = 4096, k = 512)
+_M_CHUNK = 65536
+
 
 def rescaled_entries(summary: SketchSummary, rows: jax.Array,
                      cols: jax.Array) -> jax.Array:
     """M~ evaluated at (rows, cols) — O(m k), never materializes (n1, n2).
 
     This is the pure-XLA path; repro.kernels.sampled_dot is the TPU kernel.
+    Past ``_M_CHUNK`` samples the list is zero-padded to whole chunks
+    (index 0 is always valid), mapped chunk by chunk, and cropped.
     """
+    m = rows.shape[0]
+    if m <= _M_CHUNK:
+        return _rescaled_block(summary, rows, cols)
+    pad = (-m) % _M_CHUNK
+    chunks = (jnp.pad(rows, (0, pad)).reshape(-1, _M_CHUNK),
+              jnp.pad(cols, (0, pad)).reshape(-1, _M_CHUNK))
+    out = jax.lax.map(lambda rc: _rescaled_block(summary, *rc), chunks)
+    return out.reshape(-1)[:m]
+
+
+def _rescaled_block(summary: SketchSummary, rows: jax.Array,
+                    cols: jax.Array) -> jax.Array:
     Ai = summary.A_sketch[:, rows]              # (k, m)
     Bj = summary.B_sketch[:, cols]              # (k, m)
     dots = jnp.sum(Ai * Bj, axis=0)             # (m,)

@@ -136,7 +136,8 @@ def cosketch_contribution(omega: jax.Array, psi: jax.Array,
 
     ``dY = A_chunk^T (B_chunk @ Omega_c)`` (n1, s) and
     ``dW = (Psi_c @ A_chunk^T) B_chunk`` (l, n2), both with f32
-    accumulation regardless of input dtype — the exact float ops the
+    accumulation regardless of input dtype (f32 operands stay f32 on a TPU,
+    as in ``summary_engine._sketch_dot``) — the exact float ops the
     streaming update and the one-shot ``cosketch_pass`` share (the
     bit-parity contract). A zero-row chunk contributes exact zeros (the
     monoid identity).
@@ -145,15 +146,19 @@ def cosketch_contribution(omega: jax.Array, psi: jax.Array,
     Ac, Bc = _cast(A_chunk, precision), _cast(B_chunk, precision)
     Bw = jax.lax.dot_general(Bc, _cast(omega, precision).astype(Bc.dtype),
                              dimension_numbers=(((1,), (0,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
     dY = jax.lax.dot_general(Ac, Bw.astype(Ac.dtype),
                              dimension_numbers=(((0,), (0,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
     pA = jax.lax.dot_general(_cast(psi, precision).astype(Ac.dtype), Ac,
                              dimension_numbers=(((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
     dW = jax.lax.dot_general(pA.astype(Bc.dtype), Bc,
                              dimension_numbers=(((1,), (0,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
     return dY, dW
 

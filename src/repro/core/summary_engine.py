@@ -162,11 +162,14 @@ def _sketch_dot(P: jax.Array, X: jax.Array,
 
     The freshly generated projection is cast to X's (possibly reduced)
     dtype — never the data up — so low-precision inputs hit the MXU at
-    full rate with f32 accumulation."""
+    full rate with f32 accumulation. ``Precision.HIGHEST`` keeps f32
+    operands f32 on a TPU, whose default rounds them to bf16; it changes
+    nothing for bf16 operands or on the CPU."""
     Xc = _cast(X, precision)
     return jax.lax.dot_general(
         _cast(P, precision).astype(Xc.dtype), Xc,
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 

@@ -91,8 +91,13 @@ def _ls_step(rows_from: jax.Array, cols_to: jax.Array, vals: jax.Array,
     r = F.shape[1]
     Fi = F[rows_from]                                   # (m, r)
     wv = (w * vals)[:, None] * Fi                       # (m, r)
-    outer = (w[:, None, None] * Fi[:, :, None] * Fi[:, None, :])  # (m, r, r)
-    G = jax.ops.segment_sum(outer, cols_to, num_segments=n_to)
+    # the outer products are built flat, (m, r*r): an (m, r, r) array pads
+    # its two minor dims to TPU tiles, (r, r) -> (8k, 128k) — 20x the bytes
+    # at r = 10, more than a chip holds at the paper's default m
+    ia, ib = jnp.repeat(jnp.arange(r), r), jnp.tile(jnp.arange(r), r)
+    outer = w[:, None] * Fi[:, ia] * Fi[:, ib]          # (m, r*r)
+    G = jax.ops.segment_sum(outer, cols_to,
+                            num_segments=n_to).reshape(n_to, r, r)
     b = jax.ops.segment_sum(wv, cols_to, num_segments=n_to)
     # Two-scale Tikhonov: a 1e-6-relative per-row term for conditioning plus a
     # 1e-4-relative *global* floor. Rows that draw fewer than r samples under

@@ -77,9 +77,13 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                    static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, bq: int = 128, bk: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q/k/v: (BH, S, Dh) with heads pre-expanded (GQA handled by the ops
-    wrapper). Returns (BH, S, Dh) in q's dtype."""
+    wrapper). Returns (BH, S, Dh) in q's dtype. ``interpret=None`` resolves
+    from the platform (``kernels.ops._interpret``)."""
+    if interpret is None:
+        from repro.kernels.ops import _interpret
+        interpret = _interpret()
     BH, S, Dh = q.shape
     if S % bq or S % bk:
         raise ValueError(f"flash_attention: S={S} not divisible by blocks "

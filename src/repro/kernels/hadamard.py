@@ -44,6 +44,7 @@ def _stage1_kernel(h_ref, sign_ref, x_ref, out_ref):
     xs = x_ref[...].astype(jnp.float32) * sign_ref[...].astype(jnp.float32)
     out_ref[...] = jax.lax.dot_general(
         h_ref[...], xs, dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 
@@ -51,6 +52,7 @@ def _stage2_kernel(h_ref, y_ref, out_ref):
     out_ref[...] = jax.lax.dot_general(
         h_ref[...], y_ref[...].astype(jnp.float32),
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 
@@ -58,9 +60,10 @@ def _stage2_kernel(h_ref, y_ref, out_ref):
                    static_argnames=("b", "bn", "grid_order", "interpret"))
 def blocked_fwht(X: jax.Array, signs: jax.Array, *, b: int = 128,
                  bn: int = 256, grid_order: str | None = None,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool | None = None) -> jax.Array:
     """H_d @ (signs[:, None] * X), unnormalized. X: (d, n), d = a*b, both
-    powers of two, n % bn == 0 (ops.py pads).
+    powers of two, n % bn == 0 (ops.py pads). ``interpret=None`` resolves
+    from the platform (``kernels.ops._interpret``).
 
     ``grid_order`` picks stage 1's traversal: ``None``/``'n_inner'`` walks
     n-tiles innermost (one Hb/sign stripe resident per p), ``'p_inner'``
@@ -69,6 +72,9 @@ def blocked_fwht(X: jax.Array, signs: jax.Array, *, b: int = 128,
     exactly once (no revisit/accumulation), so traversal order cannot change
     the result — bit-identical by construction, which tests/kernels assert.
     """
+    if interpret is None:
+        from repro.kernels.ops import _interpret
+        interpret = _interpret()
     d, n = X.shape
     if d % b:
         raise ValueError(f"blocked_fwht: d={d} not divisible by block b={b}")
@@ -112,16 +118,17 @@ def blocked_fwht(X: jax.Array, signs: jax.Array, *, b: int = 128,
 
     # stage 2: combine across tiles: view Y as (a, b*n), Z = Ha @ Y_mat.
     # The (d, n) row-major buffer *is* (a, b*n) row-major — a free reshape.
+    # Columns are independent, so stage 2 tiles them bn wide: an (a, bn)
+    # block keeps VMEM at O(a * bn) whatever b is.
     Ym = Y.reshape(a, b * n)
-    bm = b * bn
     Z = pl.pallas_call(
         _stage2_kernel,
-        grid=(b * n // bm,),
+        grid=(b * n // bn,),
         in_specs=[
             pl.BlockSpec((a, a), lambda c: (0, 0)),
-            pl.BlockSpec((a, bm), lambda c: (0, c)),
+            pl.BlockSpec((a, bn), lambda c: (0, c)),
         ],
-        out_specs=pl.BlockSpec((a, bm), lambda c: (0, c)),
+        out_specs=pl.BlockSpec((a, bn), lambda c: (0, c)),
         out_shape=jax.ShapeDtypeStruct((a, b * n), jnp.float32),
         interpret=interpret,
     )(Ha, Ym)

@@ -12,11 +12,12 @@ static arguments and repeat calls hit jax's compile cache):
     explicit kwarg (bn=..., precision=...)   wins over
     explicit ``config``                      wins over
     committed tuning-table hit for the shape bucket   wins over
-    ``tuning.DEFAULTS`` (the historical hard-coded values)
+    ``tuning.DEFAULTS`` (the historical hard-coded values), if they fit
+    the VMEM budget, else the best-ranked config that does
 
-With no table entry and no config the resolved blocks are exactly the old
-hard-coded defaults, so default-path outputs are bit-identical to the
-pre-tuning kernels.
+With no table entry and no config, a shape the defaults fit resolves to
+exactly the old hard-coded blocks, so default-path outputs are
+bit-identical to the pre-tuning kernels.
 """
 from __future__ import annotations
 

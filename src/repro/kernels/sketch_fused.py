@@ -13,7 +13,9 @@ Design (TPU v5e):
     HBM exactly once, the output is flushed exactly once per n-tile.
   * Block shapes are MXU-aligned (multiples of 8 x 128 for f32); the matmul
     contracts over bd with preferred_element_type=f32 so bf16 inputs hit the
-    MXU at full rate with f32 accumulation.
+    MXU at full rate with f32 accumulation. ``Precision.HIGHEST`` keeps f32
+    inputs f32 on the MXU (the default would round them to bf16), matching
+    ``summary_engine._sketch_dot``; bf16 inputs keep the single pass.
   * Column norms ride the same pass: a (1, bn) f32 row accumulated on the VPU.
 
 VMEM budget per grid step: k*bd (Pi tile) + bd*bn (A tile) + k*bn (out) +
@@ -40,6 +42,8 @@ def _kernel(pi_ref, a_ref, out_ref, norm_ref):
     out_ref[...] += jax.lax.dot_general(
         pi_ref[...], a_tile,
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=(jax.lax.Precision.HIGHEST
+                   if a_tile.dtype == jnp.float32 else None),
         preferred_element_type=jnp.float32)
     norm_ref[...] += jnp.sum(
         a_tile.astype(jnp.float32) ** 2, axis=0, keepdims=True)

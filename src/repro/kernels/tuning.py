@@ -26,7 +26,8 @@ first-class:
 * ``lookup`` — the resolution every ``kernels.ops`` wrapper uses when no
   explicit config is passed: tuning-table hit for the shape bucket, else
   the frozen ``DEFAULTS`` (bit-identical to the historical hard-coded
-  values).
+  values) where they fit ``VMEM_BUDGET_BYTES``, else the best-ranked
+  candidate that does.
 
 The tuner never changes numerics beyond float reassociation: it only
 enumerates layout knobs (blocks, grid order). ``precision`` is carried on
@@ -116,8 +117,9 @@ class KernelConfig(NamedTuple):
 
 
 #: The frozen historical defaults — ``lookup`` falls back to these on a
-#: table miss, which is what keeps default-config results bit-identical
-#: to the pre-tuning hard-coded kernels.
+#: table miss when they fit the VMEM budget, which is what keeps
+#: default-config results bit-identical to the pre-tuning hard-coded
+#: kernels.
 DEFAULTS: Dict[str, KernelConfig] = {
     "sketch_fused": KernelConfig("sketch_fused", (256, 512)),
     "blocked_fwht": KernelConfig("blocked_fwht", (128, 256)),
@@ -244,11 +246,13 @@ def vmem_bytes(cfg: KernelConfig, shape: Tuple[int, ...]) -> int:
         b = min(b, d)
         a = d // b
         stage1 = 4 * (b * b + 2 * (b + b * bn) + b * bn)
-        stage2 = 0 if a <= 1 else 4 * (a * a + 3 * a * b * bn)
+        stage2 = 0 if a <= 1 else 4 * (a * a + 3 * a * bn)
         return max(stage1, stage2)
     if cfg.kernel == "sampled_dot":
         n1, n2, k, m = shape
-        return 4 * (4 * k + n1 + n2 + 2)
+        # two gathered rows double-buffered + the output element; the
+        # norm vectors and the indices sit in SMEM
+        return 4 * (4 * k + 2)
     if cfg.kernel == "flash_attention":
         BH, S, Dh = shape
         bq, bk = (min(b, S) for b in cfg.block)
@@ -311,7 +315,7 @@ def roofline_cost(cfg: KernelConfig, shape: Tuple[int, ...], *,
         if a > 1:
             hbm += 8 * d * np_ + 4 * a * a
             flops += 2.0 * d * np_ * a
-            steps += np_ // bn
+            steps += b * np_ // bn
         occ = _mxu_occupancy(b, bn)
     elif cfg.kernel == "sampled_dot":
         n1, n2, k, m = shape
@@ -629,13 +633,20 @@ def reload_tables() -> None:
 def lookup(kernel: str, shape: Tuple[int, ...], *, dtype_bytes: int = 4,
            backend: Optional[str] = None) -> KernelConfig:
     """The ops-wrapper resolution: committed-table hit for the shape
-    bucket, else the frozen default. Never returns None and never changes
-    numerics — an unknown shape gets exactly the historical block sizes.
+    bucket, else the frozen default when its VMEM working set fits
+    ``VMEM_BUDGET_BYTES``, else the roofline ranking's head among the
+    candidates that fit (a layout change only: float reassociation at
+    most). Never returns None; a shape the default fits gets exactly the
+    historical block sizes.
     """
     if kernel not in KERNELS:
         raise ValueError(f"unknown kernel {kernel!r} (use one of {KERNELS})")
     hit = builtin_table(backend).get(kernel, shape, dtype_bytes)
-    return hit if hit is not None else DEFAULTS[kernel]
+    if hit is not None:
+        return hit
+    if vmem_bytes(DEFAULTS[kernel], shape) <= VMEM_BUDGET_BYTES:
+        return DEFAULTS[kernel]
+    return rank_candidates(kernel, shape, dtype_bytes=dtype_bytes)[0]
 
 
 def dtype_bytes_of(x) -> int:

@@ -22,6 +22,7 @@ import json
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models import build
 from repro.serve.engine import Engine, ServeConfig
@@ -112,6 +113,7 @@ def main(argv=None):
     ap.add_argument("--m", type=int, default=800)
     ap.add_argument("--T", type=int, default=3)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     out = run_sketch(args) if args.mode == "sketch" else run_generate(args)
     print(json.dumps(out))

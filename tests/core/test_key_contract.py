@@ -7,10 +7,14 @@ These tests freeze that tree as hard-coded uint32 key data for
 ``PRNGKey(0)`` — a refactor that silently moves a split or fold_in now fails
 here instead of invisibly invalidating every reproducibility claim.
 
-Golden values were recorded from the jax threefry2x32 PRNG (the default;
-stable across jax versions by design). Each test ALSO checks the public
-entry point consumes the derived key (composition equality), so the goldens
-pin behavior, not just documentation.
+Golden values were recorded from the jax threefry2x32 PRNG (the default)
+under ``jax_threefry_partitionable=True``, the default of the installed jax
+line. That flag changes what ``split`` returns (with it on,
+``split(key, n)[i] == fold_in(key, i)``), so the split-derived goldens hold
+for that setting only; ``fold_in`` values are the same under both. Each
+test ALSO checks the public entry point consumes the derived key
+(composition equality), so the goldens pin behavior, not just
+documentation.
 """
 import jax
 import jax.numpy as jnp
@@ -23,13 +27,13 @@ from repro.core.error_engine import probe_key, probe_omega
 KEY0 = [0, 0]                     # PRNGKey(0) raw key data
 
 # split(PRNGKey(0), 3) — smppca's (k_sketch, k_sample, k_als) layout
-SMPPCA_SPLIT3 = [[2467461003, 428148500],
-                 [3186719485, 3840466878],
-                 [2562233961, 1946702221]]
+SMPPCA_SPLIT3 = [[1797259609, 2579123966],
+                 [928981903, 3453687069],
+                 [4146024105, 2718843009]]
 # fold_in(k_sample, 0) — the key smppca hands to estimate_product
-SMPPCA_EST_KEY = [3085582442, 3617870444]
+SMPPCA_EST_KEY = [346279018, 360566543]
 # split(SMPPCA_EST_KEY) — estimation's (sample key, ALS key)
-EST_SPLIT2 = [[3818717833, 1612203793], [166711035, 3635324495]]
+EST_SPLIT2 = [[2067023257, 4011464742], [282707546, 681628770]]
 
 # fold_in(PRNGKey(0), i) — the per-row gaussian projection keys
 ROW_KEYS = {0: [1797259609, 2579123966],
@@ -38,7 +42,7 @@ ROW_KEYS = {0: [1797259609, 2579123966],
 
 # split(PRNGKey(0)) — srht_plan's (sign key, row-sample key); sketch_svd and
 # estimate_product share the same single split of their own base key
-SPLIT2 = [[4146024105, 967050713], [2718843009, 1272950319]]
+SPLIT2 = [[1797259609, 2579123966], [928981903, 3453687069]]
 
 # fold_in(PRNGKey(0), 1) — SketchService's per-request estimation key
 SERVICE_EST_KEY = [928981903, 3453687069]
@@ -167,8 +171,12 @@ def test_sketch_service_key_tree(key):
                                            block=32)
     manual = estimation_engine.estimate_product(
         jnp.asarray(SERVICE_EST_KEY, jnp.uint32), summary, 2, m=100, T=2)
-    np.testing.assert_array_equal(np.asarray(served.factors.U),
-                                  np.asarray(manual.factors.U))
+    # the served path is vmapped over the batch, the manual one is not:
+    # XLA may fuse the two differently, so they agree to float rounding (a
+    # wrong key would change every entry at O(1))
+    np.testing.assert_allclose(np.asarray(served.factors.U),
+                               np.asarray(manual.factors.U),
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_pipeline_plan_key_tree(key):

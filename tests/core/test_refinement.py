@@ -396,7 +396,9 @@ def test_pipeline_refine_joins_cache_key(key):
 
 def test_service_stream_refined_matches_one_shot(key):
     """stream_factors with a co-sketch-carrying service reproduces one-shot
-    flush_factors bit-for-bit under method='power' + refine."""
+    flush_factors under method='power' + refine. The summaries are
+    bit-identical; the flush estimates a batch of one under vmap, which
+    XLA may fuse differently, so the factors agree to f32 rounding."""
     from repro.serve.engine import SketchService
     A, B = gaussian_pair(key, d=64)
     svc = SketchService(k=8, backend="scan", block=32, cosketch=3)
@@ -408,10 +410,11 @@ def test_service_stream_refined_matches_one_shot(key):
     svc.append(sid, A[32:], B[32:])
     est = svc.stream_factors(sid, r=2, est_method="power",
                              refine=RefineSpec(1, "power"))
-    np.testing.assert_array_equal(np.asarray(est.factors.U),
-                                  np.asarray(served.factors.U))
-    np.testing.assert_array_equal(np.asarray(est.factors.V),
-                                  np.asarray(served.factors.V))
+    for got, want in ((est.factors.U, served.factors.U),
+                      (est.factors.V, served.factors.V)):
+        want = np.asarray(want)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                                   atol=1e-5 * np.abs(want).max())
 
 
 def test_batched_power_estimation(key):

@@ -111,8 +111,10 @@ def test_sampled_dot_empty_sample_set():
 
 def test_sampled_dot_more_samples_than_entries():
     """m > n1 * n2: the sample necessarily repeats entries — every duplicate
-    gathers the identical sketch rows and the kernel matches the oracle
-    exactly (parity, not tolerance: same f32 ops per grid step)."""
+    gathers the identical sketch rows, so duplicates agree bit-for-bit
+    among themselves (same f32 ops per grid step), and the kernel matches
+    the oracle to f32 rounding (the oracle's reductions run vectorized over
+    all samples, which XLA may fuse and order differently)."""
     kk = jax.random.PRNGKey(7)
     As = jax.random.normal(kk, (5, 8))
     Bs = jax.random.normal(jax.random.fold_in(kk, 1), (4, 8))
@@ -123,7 +125,8 @@ def test_sampled_dot_more_samples_than_entries():
     cols = jax.random.randint(jax.random.fold_in(kk, 5), (m,), 0, 4)
     got = ops.sampled_rescaled_dot(As, Bs, na, nb, rows, cols)
     want = ref.sampled_rescaled_dot_ref(As, Bs, na, nb, rows, cols)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
     # duplicates really occurred and agree among themselves
     pairs = np.stack([np.asarray(rows), np.asarray(cols)], 1)
     _, inv = np.unique(pairs, axis=0, return_inverse=True)

