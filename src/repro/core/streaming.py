@@ -92,6 +92,7 @@ from typing import Iterable, NamedTuple, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.core.summary_engine import (
     METHODS, _cast, _sketch_dot, projection_rows, srht_plan)
 from repro.core.types import SketchSummary
@@ -349,23 +350,28 @@ def _chunk_contribution(key, signs, srows, A_chunk, B_chunk, gids, *,
     """(dA, dB, dna2, dnb2) for one chunk of rows with global ids ``gids``.
 
     Performs the exact float ops of the scan backend's body — the basis of
-    the bit-parity guarantee for aligned sequential ingestion.
+    the bit-parity guarantee for aligned sequential ingestion. The stages
+    carry the names ``sketch`` and ``norms`` in the device trace.
     """
     plan = None if method == "gaussian" else (signs, srows)
-    P = projection_rows(key, gids, k, method=method, plan=plan)
     Ac, Bc = _cast(A_chunk, precision), _cast(B_chunk, precision)
-    return (_sketch_dot(P, Ac, precision),
-            _sketch_dot(P, Bc, precision),
-            jnp.sum(Ac.astype(jnp.float32) ** 2, axis=0),
-            jnp.sum(Bc.astype(jnp.float32) ** 2, axis=0))
+    with jax.named_scope("sketch"):
+        P = projection_rows(key, gids, k, method=method, plan=plan)
+        dA, dB = _sketch_dot(P, Ac, precision), _sketch_dot(P, Bc, precision)
+    with jax.named_scope("norms"):
+        dna2 = jnp.sum(Ac.astype(jnp.float32) ** 2, axis=0)
+        dnb2 = jnp.sum(Bc.astype(jnp.float32) ** 2, axis=0)
+    return dA, dB, dna2, dnb2
 
 
 @functools.partial(jax.jit, static_argnames=("precision",))
 def _probe_chunk(omega, A_chunk, B_chunk, *, precision: Optional[str]):
     """(n1, p) probe delta for one chunk — the exact float ops of the
-    one-shot ``error_engine.probe_pass`` scan body (bit-parity contract)."""
+    one-shot ``error_engine.probe_pass`` scan body (bit-parity contract),
+    named ``probe`` in the device trace."""
     from repro.core.error_engine import probe_contribution
-    return probe_contribution(omega, A_chunk, B_chunk, precision)
+    with jax.named_scope("probe"):
+        return probe_contribution(omega, A_chunk, B_chunk, precision)
 
 
 @functools.partial(jax.jit, static_argnames=("precision",))
@@ -560,44 +566,60 @@ class StreamingSummarizer:
         still executing — jax dispatch is asynchronous, so the copy for
         chunk ``c+1`` overlaps chunk ``c``'s compute and the pass approaches
         memory-bandwidth speed instead of alternating copy/compute.
-        ``prefetch=0`` degrades to the serial copy-then-update loop (the
-        overlap-off baseline the ingest benchmark measures against).
+        ``prefetch=0`` degrades to the serial copy-then-update loop.
+        The chip benchmark's ``stream4k.ingest`` cell measures this call.
 
         The math is untouched: staging only moves bytes, so ``ingest`` is
         **bit-identical** to the equivalent ``update`` loop at the same
         chunk boundaries (tested in tests/core/test_streaming_ingest.py).
         Chunks start at ``row_offset`` (default: the state's ``row_high``
         cursor — the resume-contiguously convention of ``serve.engine``).
+
+        Each call records ``repro.telemetry`` spans: ``repro.ingest``
+        around the whole call and, inside it, ``repro.ingest.feed`` per
+        pull from ``chunks`` (one more than the chunks when the source
+        ends), ``repro.ingest.stage`` per chunk's ``device_put`` and
+        ``repro.ingest.update`` per chunk's ``update`` launch.
         """
         if isinstance(prefetch, bool) or not isinstance(prefetch, int) \
                 or prefetch < 0:
             raise ValueError(
                 f"prefetch must be a non-negative chunk count, "
                 f"got {prefetch!r}")
-        off = int(state.row_high) if row_offset is None else int(row_offset)
-        it = iter(chunks)
-        staged: collections.deque = collections.deque()
+        with telemetry.span("repro.ingest"):
+            off = (int(state.row_high) if row_offset is None
+                   else int(row_offset))
+            it = iter(chunks)
+            staged: collections.deque = collections.deque()
 
-        def _stage_next() -> None:
-            try:
-                A_chunk, B_chunk = next(it)
-            except StopIteration:
-                return
-            staged.append((jax.device_put(A_chunk), jax.device_put(B_chunk)))
+            def _stage_next() -> None:
+                nonlocal it
+                if it is None:                  # the source has ended
+                    return
+                try:
+                    with telemetry.span("repro.ingest.feed"):
+                        A_chunk, B_chunk = next(it)
+                except StopIteration:
+                    it = None
+                    return
+                with telemetry.span("repro.ingest.stage"):
+                    staged.append((jax.device_put(A_chunk),
+                                   jax.device_put(B_chunk)))
 
-        for _ in range(prefetch + 1):       # prime the pipeline
-            _stage_next()
-        while staged:
-            A_chunk, B_chunk = staged.popleft()
-            # enqueue the next host->device copy BEFORE dispatching the
-            # update when running serial (prefetch=0) would instead wait
-            if prefetch:
+            for _ in range(prefetch + 1):       # prime the pipeline
                 _stage_next()
-            state = self.update(state, A_chunk, B_chunk, off)
-            off += A_chunk.shape[0]
-            if not prefetch:
-                jax.block_until_ready(state.A_acc)
-                _stage_next()
+            while staged:
+                A_chunk, B_chunk = staged.popleft()
+                # enqueue the next host->device copy BEFORE dispatching the
+                # update when running serial (prefetch=0) would instead wait
+                if prefetch:
+                    _stage_next()
+                with telemetry.span("repro.ingest.update"):
+                    state = self.update(state, A_chunk, B_chunk, off)
+                off += A_chunk.shape[0]
+                if not prefetch:
+                    jax.block_until_ready(state.A_acc)
+                    _stage_next()
         return state
 
     def _absorb(self, state, A_chunk, B_chunk, gids, t, hi1) -> StreamState:

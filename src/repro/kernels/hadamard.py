@@ -111,6 +111,7 @@ def blocked_fwht(X: jax.Array, signs: jax.Array, *, b: int = 128,
         out_specs=pl.BlockSpec((b, bn), ix),
         out_shape=jax.ShapeDtypeStruct((d, n), jnp.float32),
         interpret=interpret,
+        name="fwht_stage1",
     )(Hb, signs.reshape(d, 1), X)
 
     if a == 1:
@@ -131,5 +132,6 @@ def blocked_fwht(X: jax.Array, signs: jax.Array, *, b: int = 128,
         out_specs=pl.BlockSpec((a, bn), lambda c: (0, c)),
         out_shape=jax.ShapeDtypeStruct((a, b * n), jnp.float32),
         interpret=interpret,
+        name="fwht_stage2",
     )(Ha, Ym)
     return Z.reshape(d, n)

@@ -69,6 +69,7 @@ def _launch(As3, Bs3, norm_A, norm_B, rows, cols, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, 1, 1), jnp.float32),
         interpret=interpret,
+        name="sampled_dot",
     )(rows, cols, norm_A, norm_B, As3, Bs3)
     return out[:, 0, 0]
 

@@ -100,5 +100,6 @@ def sketch_fused(Pi: jax.Array, A: jax.Array, *, bn: int = 256, bd: int = 512,
             jax.ShapeDtypeStruct((1, n), jnp.float32),
         ],
         interpret=interpret,
+        name="sketch_fused",
     )(Pi, A)
     return out, norm2[0]

@@ -26,6 +26,7 @@ except ImportError:
     from tests._hyp import given, settings
     from tests._hyp import strategies as st
 
+from repro import telemetry
 from repro.core import streaming
 from repro.core.streaming import (
     StreamingSummarizer, WindowedSummarizer, WireSpec, choose_wire_spec,
@@ -93,6 +94,26 @@ def test_ingest_resumes_from_row_high():
     got = summ.ingest(summ.init(_KEY, (_D, _NA, _NB)), [(A[:32], B[:32])])
     got = summ.ingest(got, [(A[32:64], B[32:64])])   # offset = row_high
     _assert_tree_equal(got, ref)
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_ingest_records_one_call_and_its_children(prefetch):
+    summ = StreamingSummarizer(8, probes=4)
+    A, B = _pair()
+    n = _D // 32
+    summ.ingest(summ.init(_KEY, (_D, _NA, _NB)),
+                ((A[off:off + 32], B[off:off + 32])
+                 for off in range(0, _D, 32)),
+                prefetch=prefetch)
+    occ = telemetry.occurrences()
+    call = max(i for i, o in enumerate(occ) if o.name == "repro.ingest")
+    children = [o.name for o in occ if o.parent == call]
+    # the source is pulled once more, to find that it has ended
+    assert sorted(children) == sorted(["repro.ingest.feed"] * (n + 1)
+                                      + ["repro.ingest.stage"] * n
+                                      + ["repro.ingest.update"] * n)
+    assert occ[call].children == len(children)
+    assert occ[call].parent is None
 
 
 def test_ingest_rejects_bad_prefetch():

@@ -4,7 +4,8 @@ Interpret mode runs the kernel bodies on the CPU, but it cannot see what
 Mosaic refuses: blocks not aligned to the (8, 128) tiling, or more VMEM
 than a kernel may use. These tests compile each kernel for a described
 (not attached) ``v5e:2x2`` chip and check that the result holds the
-Mosaic kernel (``tpu_custom_call``). Nothing runs, so they say nothing
+Mosaic kernel (``tpu_custom_call``) under the kernel's stable name, the
+one the device trace shows. Nothing runs, so they say nothing
 about results or times.
 
 The topology is described inside a module fixture, never at import: only
@@ -50,8 +51,11 @@ def _compile(fn, one_chip, *shapes):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _assert_mosaic(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_mosaic(compiled, *names):
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    for name in names:
+        assert f"%{name}." in text, name
 
 
 @pytest.mark.parametrize("precision", [None, "bf16"])
@@ -61,7 +65,7 @@ def test_sketch_fused_compiles_for_v5e(one_chip, precision):
         lambda Pi, A: sketch_fused.sketch_fused(
             Pi, A, bn=bn, bd=bd, interpret=False, precision=precision),
         one_chip, ((K, D_CHUNK), jnp.float32), ((D_CHUNK, N), jnp.float32))
-    _assert_mosaic(compiled)
+    _assert_mosaic(compiled, "sketch_fused")
 
 
 def test_sampled_dot_compiles_for_v5e(one_chip):
@@ -71,7 +75,7 @@ def test_sampled_dot_compiles_for_v5e(one_chip):
         one_chip, ((N, K), jnp.float32), ((N, K), jnp.float32),
         ((N,), jnp.float32), ((N,), jnp.float32),
         ((M,), jnp.int32), ((M,), jnp.int32))
-    _assert_mosaic(compiled)
+    _assert_mosaic(compiled, "sampled_dot")
 
 
 def test_blocked_fwht_compiles_for_v5e(one_chip):
@@ -83,4 +87,4 @@ def test_blocked_fwht_compiles_for_v5e(one_chip):
             X, signs, b=b, bn=bn, grid_order=cfg.grid_order,
             interpret=False),
         one_chip, ((D_CHUNK, N), jnp.float32), ((D_CHUNK,), jnp.float32))
-    _assert_mosaic(compiled)
+    _assert_mosaic(compiled, "fwht_stage1", "fwht_stage2")
