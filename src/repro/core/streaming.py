@@ -94,7 +94,7 @@ import jax.numpy as jnp
 
 from repro import telemetry
 from repro.core.summary_engine import (
-    METHODS, _cast, _sketch_dot, projection_rows, srht_plan)
+    METHODS, _cast, _sketch_pair, projection_rows, srht_plan)
 from repro.core.types import SketchSummary
 
 
@@ -357,7 +357,7 @@ def _chunk_contribution(key, signs, srows, A_chunk, B_chunk, gids, *,
     Ac, Bc = _cast(A_chunk, precision), _cast(B_chunk, precision)
     with jax.named_scope("sketch"):
         P = projection_rows(key, gids, k, method=method, plan=plan)
-        dA, dB = _sketch_dot(P, Ac, precision), _sketch_dot(P, Bc, precision)
+        dA, dB = _sketch_pair(P, Ac, Bc, precision)
     with jax.named_scope("norms"):
         dna2 = jnp.sum(Ac.astype(jnp.float32) ** 2, axis=0)
         dnb2 = jnp.sum(Bc.astype(jnp.float32) ** 2, axis=0)

@@ -173,6 +173,19 @@ def _sketch_dot(P: jax.Array, X: jax.Array,
         preferred_element_type=jnp.float32)
 
 
+def _sketch_pair(P: jax.Array, A: jax.Array, B: jax.Array,
+                 precision: Optional[str]):
+    """``(P^T A, P^T B)`` for one chunk's or block's projection rows ``P``.
+
+    The barrier makes ``P`` one value that both dots read. Without it the
+    compiler fuses the projection's generation into each dot, and a TPU
+    tiles each dot's output columns and regenerates ``P`` for every tile.
+    The values, and so the products, are the same; they are only computed
+    once, at the cost of holding the (t, k) block in memory."""
+    P = jax.lax.optimization_barrier(P)
+    return _sketch_dot(P, A, precision), _sketch_dot(P, B, precision)
+
+
 # ---------------------------------------------------------------------------
 # Backends
 # ---------------------------------------------------------------------------
@@ -197,7 +210,8 @@ def _reference_backend(key, A, B, k: int, *, method: str = "gaussian",
 def _rows_backend(key, A, B, k: int, *, method: str = "gaussian",
                   block: int = 1024, precision: Optional[str] = None,
                   tuning=None) -> SketchSummary:
-    """Row-stream semantics over the full in-memory pair (rows 0..d-1)."""
+    """Row-stream semantics over the full in-memory pair (rows 0..d-1),
+    as one block: its (d, k) projection is held in memory once."""
     del block, tuning
     d = A.shape[0]
     return rows_summary(key, jnp.arange(d), A, B, k, method=method,
@@ -219,9 +233,8 @@ def rows_summary(key: jax.Array, row_idx: jax.Array, A_rows: jax.Array,
     P = projection_rows(key, row_idx, k, method=method, d_total=d_total,
                         plan=plan)
     Ac, Bc = _cast(A_rows, precision), _cast(B_rows, precision)
-    return SketchSummary(
-        _sketch_dot(P, Ac, precision), _sketch_dot(P, Bc, precision),
-        column_norms(Ac), column_norms(Bc))
+    return SketchSummary(*_sketch_pair(P, Ac, Bc, precision),
+                         column_norms(Ac), column_norms(Bc))
 
 
 @register_backend("scan")
@@ -260,8 +273,8 @@ def _scan_backend(key, A, B, k: int, *, method: str = "gaussian",
         else:
             P_b = srht_rows_from_plan(sb, srows, gids, k)
         Ac, Bc = _cast(Ab, precision), _cast(Bb, precision)
-        As = As + _sketch_dot(P_b, Ac, precision)
-        Bs = Bs + _sketch_dot(P_b, Bc, precision)
+        dA, dB = _sketch_pair(P_b, Ac, Bc, precision)
+        As, Bs = As + dA, Bs + dB
         na2 = na2 + jnp.sum(Ac.astype(jnp.float32) ** 2, axis=0)
         nb2 = nb2 + jnp.sum(Bc.astype(jnp.float32) ** 2, axis=0)
         return (As, Bs, na2, nb2), None

@@ -50,6 +50,27 @@ def test_backend_parity_vs_reference(key, method, backend):
         _assert_summary_close(got, ref)
 
 
+@pytest.mark.parametrize("precision", [None, "bf16"])
+@pytest.mark.parametrize("method", ["gaussian", "srht"])
+def test_sketch_pair_matches_two_sketch_dots(key, method, precision):
+    """Pinning one block's projection changes no bit of either sketch."""
+    A, B = _pair(key, d=256)
+    gids = 512 + jnp.arange(256)
+    plan = se.srht_plan(key, 1024, 32)[:2] if method == "srht" else None
+
+    def _projection(key):
+        return se.projection_rows(key, gids, 32, method=method, plan=plan)
+
+    pair = jax.jit(lambda key, A, B: se._sketch_pair(
+        _projection(key), A, B, precision))(key, A, B)
+    dots = jax.jit(lambda key, A, B: (
+        se._sketch_dot(_projection(key), A, precision),
+        se._sketch_dot(_projection(key), B, precision)))(key, A, B)
+    for got, want, name in zip(pair, dots, ("A", "B")):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                      err_msg=name)
+
+
 @pytest.mark.dist
 def test_distributed_backend_parity():
     """2-shard CPU mesh vs reference, both methods (subprocess: the main

@@ -11,15 +11,18 @@ about results or times.
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every worker imports
 every test file. Block sizes come from ``tuning.lookup``, as on the
-program's default path.
+program's default path. One more test reads the compiled ingest update
+(``_chunk_contribution``) for where the chunk's projection is generated.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import streaming
 from repro.kernels import hadamard, sampled_dot, sketch_fused, tuning
 
 K, D_CHUNK, N = 512, 16384, 4096      # the chip smoke's ingest widths
@@ -88,3 +91,46 @@ def test_blocked_fwht_compiles_for_v5e(one_chip):
             interpret=False),
         one_chip, ((D_CHUNK, N), jnp.float32), ((D_CHUNK,), jnp.float32))
     _assert_mosaic(compiled, "fwht_stage1", "fwht_stage2")
+
+
+def _computations(text):
+    """{name: body} of each computation in a compiled module's text."""
+    return {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%(\S+) \([^\n]*\{\n(.*?)^\}$", text, re.M | re.S)}
+
+
+def _called(comps, name):
+    """The computation ``name`` and every one it calls, transitively."""
+    seen, todo = set(), [name]
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo += re.findall(r"calls=%([\w.\-]+)", comps[c])
+    return seen
+
+
+@pytest.mark.parametrize("method", ["gaussian", "srht"])
+def test_chunk_projection_generated_once_for_v5e(one_chip, method):
+    """One chunk's projection block is one fusion that both sketch dots
+    read, not a producer regenerated inside each dot's tiles."""
+    plan = ((((2 ** 20,), jnp.float32), ((K,), jnp.int32))
+            if method == "srht" else ())
+    compiled = _compile(
+        lambda key, A, B, gids, *sr: streaming._chunk_contribution(
+            key, *(sr or (None, None)), A, B, gids, k=K, method=method,
+            precision=None),
+        one_chip, ((2,), jnp.uint32), ((D_CHUNK, N), jnp.float32),
+        ((D_CHUNK, N), jnp.float32), ((D_CHUNK,), jnp.int32), *plan)
+    text = compiled.as_text()
+    comps = _computations(text)
+    entry = re.search(r"^ENTRY %(\S+) ", text, re.M).group(1)
+    producers = re.findall(rf"= f32\[{D_CHUNK},{K}\]\S* fusion\(",
+                           comps[entry])
+    assert len(producers) == 1
+    generation = " xor(" if method == "gaussian" else " popcnt("
+    dots = [c for c in re.findall(r"calls=%([\w.\-]+)", comps[entry])
+            if any(" convolution(" in comps[f] for f in _called(comps, c))]
+    assert len(dots) == 2
+    for dot in dots:
+        assert not any(generation in comps[f] for f in _called(comps, dot))
