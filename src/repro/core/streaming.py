@@ -94,8 +94,9 @@ import jax.numpy as jnp
 
 from repro import telemetry
 from repro.core.summary_engine import (
-    METHODS, _cast, _sketch_pair, projection_rows, srht_plan)
-from repro.core.types import SketchSummary
+    METHODS, _cast, _sketch_pair, projection_rows, sparse_summary_pass,
+    srht_plan)
+from repro.core.types import SketchSummary, SparseRows
 
 
 class StreamState(NamedTuple):
@@ -344,6 +345,15 @@ def finalize_state(state: StreamState) -> SketchSummary:
                          cosketch_psi=state.cosketch_psi)
 
 
+def _sparse_pair(A_chunk, B_chunk) -> bool:
+    """Whether a chunk pair is ``SparseRows``; both or neither must be."""
+    sparse = isinstance(A_chunk, SparseRows)
+    if sparse != isinstance(B_chunk, SparseRows):
+        raise ValueError("A and B chunks must both be SparseRows or both "
+                         "be dense arrays")
+    return sparse
+
+
 @functools.partial(jax.jit, static_argnames=("k", "method", "precision"))
 def _chunk_contribution(key, signs, srows, A_chunk, B_chunk, gids, *,
                         k: int, method: str, precision: Optional[str]):
@@ -362,6 +372,17 @@ def _chunk_contribution(key, signs, srows, A_chunk, B_chunk, gids, *,
         dna2 = jnp.sum(Ac.astype(jnp.float32) ** 2, axis=0)
         dnb2 = jnp.sum(Bc.astype(jnp.float32) ** 2, axis=0)
     return dA, dB, dna2, dnb2
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _sparse_contribution(key, omega, A_chunk: SparseRows,
+                         B_chunk: SparseRows, gids, *, k: int):
+    """(dA, dB, dna2, dnb2, probe delta or None) for one ``SparseRows``
+    chunk pair with global row ids ``gids``: the same projection rows as
+    the dense path's for those ids, every sum in f32
+    (``summary_engine.sparse_summary_pass``)."""
+    P = projection_rows(key, gids, k)
+    return sparse_summary_pass(P, A_chunk, B_chunk, omega)
 
 
 @functools.partial(jax.jit, static_argnames=("precision",))
@@ -567,7 +588,8 @@ class StreamingSummarizer:
         chunk ``c+1`` overlaps chunk ``c``'s compute and the pass approaches
         memory-bandwidth speed instead of alternating copy/compute.
         ``prefetch=0`` degrades to the serial copy-then-update loop.
-        The chip benchmark's ``stream4k.ingest`` cell measures this call.
+        The chip benchmark's ``stream4k.ingest`` cell measures this call,
+        and ``nytbow.ingest`` with ``SparseRows`` chunks.
 
         The math is untouched: staging only moves bytes, so ``ingest`` is
         **bit-identical** to the equivalent ``update`` loop at the same
@@ -626,17 +648,37 @@ class StreamingSummarizer:
         if A_chunk.shape[0] != B_chunk.shape[0]:
             raise ValueError(f"chunk row counts differ: "
                              f"{A_chunk.shape} vs {B_chunk.shape}")
+        sparse = _sparse_pair(A_chunk, B_chunk)
+        if sparse:
+            off = [name for name, on in (
+                ("method='srht'", self.method == "srht"),
+                ("cosketch", state.cosketch_Y is not None),
+                ("decay", state.decay_rate is not None),
+                ("precision='bf16'", self.precision == "bf16")) if on]
+            if off:
+                raise NotImplementedError(
+                    f"a SparseRows chunk cannot be absorbed with "
+                    f"{', '.join(off)}: the sparse path is gaussian and f32, "
+                    f"without co-sketch or decay (docs/streaming.md, "
+                    f"'Sparse rows')")
         # Settle pending decay *before* absorbing: new rows enter at weight
         # 1 (they arrive "now"), old mass is physically scaled down so
         # accumulator magnitudes stay bounded on long decayed streams.
         state = _settle_state(state)
-        dA, dB, dna2, dnb2 = _chunk_contribution(
-            state.key, state.signs, state.srows, A_chunk, B_chunk, gids,
-            k=self.k, method=self.method, precision=self.precision)
         probe_acc = state.probe_acc
-        if state.omega is not None:
-            probe_acc = probe_acc + _probe_chunk(
-                state.omega, A_chunk, B_chunk, precision=self.precision)
+        if sparse:
+            dA, dB, dna2, dnb2, dprobe = _sparse_contribution(
+                state.key, state.omega, A_chunk, B_chunk, gids, k=self.k)
+            if dprobe is not None:
+                probe_acc = probe_acc + dprobe
+        else:
+            dA, dB, dna2, dnb2 = _chunk_contribution(
+                state.key, state.signs, state.srows, A_chunk, B_chunk,
+                gids, k=self.k, method=self.method,
+                precision=self.precision)
+            if state.omega is not None:
+                probe_acc = probe_acc + _probe_chunk(
+                    state.omega, A_chunk, B_chunk, precision=self.precision)
         c_Y, c_W = state.cosketch_Y, state.cosketch_W
         if state.cosketch_omega is not None:
             dY, dW = _cosketch_chunk(
@@ -974,6 +1016,15 @@ class WindowState(NamedTuple):
         return len(self.buckets)
 
 
+def _dense_only(A_chunk, B_chunk):
+    """The pair, unless it is ``SparseRows``, which windows do not take."""
+    if _sparse_pair(A_chunk, B_chunk):
+        raise NotImplementedError(
+            "the windowed summarizer does not take SparseRows chunks "
+            "(docs/streaming.md, 'Sparse rows')")
+    return A_chunk, B_chunk
+
+
 class WindowedSummarizer:
     """Sliding-window front-end: the summary of the last ``n_buckets`` epochs.
 
@@ -1092,6 +1143,7 @@ class WindowedSummarizer:
         """Absorb a contiguous chunk into the head epoch (bucket-local
         ``row_offset``)."""
         self._check_ring(wstate)
+        _dense_only(A_chunk, B_chunk)
         slot = int(wstate.head) % self.n_buckets
         return self._with_head_bucket(wstate, self._inner.update(
             wstate.buckets[slot], A_chunk, B_chunk, row_offset))
@@ -1100,6 +1152,7 @@ class WindowedSummarizer:
                     B_rows) -> WindowState:
         """Absorb rows with explicit bucket-local ids into the head epoch."""
         self._check_ring(wstate)
+        _dense_only(A_rows, B_rows)
         slot = int(wstate.head) % self.n_buckets
         return self._with_head_bucket(wstate, self._inner.update_rows(
             wstate.buckets[slot], row_ids, A_rows, B_rows))
@@ -1112,6 +1165,7 @@ class WindowedSummarizer:
         inner ``StreamingSummarizer.ingest`` on the head bucket (same
         overlap, same bit-parity contract, bucket-local row ids)."""
         self._check_ring(wstate)
+        chunks = (_dense_only(A, B) for A, B in chunks)
         slot = int(wstate.head) % self.n_buckets
         return self._with_head_bucket(wstate, self._inner.ingest(
             wstate.buckets[slot], chunks, row_offset=row_offset,

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 
 from repro.core.sketch import (
     _next_pow2, column_norms, gaussian_pi, pi_rows)
-from repro.core.types import SketchSummary
+from repro.core.types import SketchSummary, SparseRows
 
 METHODS = ("gaussian", "srht")
 
@@ -184,6 +184,41 @@ def _sketch_pair(P: jax.Array, A: jax.Array, B: jax.Array,
     once, at the cost of holding the (t, k) block in memory."""
     P = jax.lax.optimization_barrier(P)
     return _sketch_dot(P, A, precision), _sketch_dot(P, B, precision)
+
+
+def sparse_summary_pass(P: jax.Array, A: SparseRows, B: SparseRows,
+                        omega: Optional[jax.Array] = None):
+    """One sparse chunk pair's summands: ``(P^T A, P^T B, squared column
+    norms of A and of B, A^T (B omega) or None)``, all f32, for the
+    chunk's ``(t, k)`` projection rows ``P`` and the ``(n2, p)`` probes.
+
+    Each operand takes one pass of the ``sparse_rows`` kernel over its
+    entries, sorted by column: an entry adds its value times its row of
+    ``[P | B omega]`` to its column's row of the output, and its value
+    squared to the row's last lane (the norms). ``B omega`` is one more
+    pass, over B's entries by row. So the chunk is never densified and no
+    (entries, k) gather exists. Stages: ``sparse_probe`` (``B omega``),
+    ``sparse_sketch`` (the two passes), ``sparse_norms`` (reading the
+    norms' lane)."""
+    from repro.kernels.sparse_rows import rows_accumulate
+    t, k = P.shape
+    p = 0 if omega is None else omega.shape[1]
+    lanes = -(-(k + p + 1) // 128) * 128
+    cols = [P.astype(jnp.float32)]
+    if p:
+        with jax.named_scope("sparse_probe"):
+            cols.append(rows_accumulate(B.vals, B.cols, B.rows, omega,
+                                        n_out=t))
+    cols.append(jnp.zeros((t, lanes - k - p), jnp.float32))
+    M = jnp.concatenate(cols, axis=1).reshape(t, lanes // 128, 128)
+    with jax.named_scope("sparse_sketch"):
+        outs = [rows_accumulate(X.vals, X.rows, X.cols, M, n_out=X.shape[1],
+                                squares=True).reshape(X.shape[1], lanes)
+                for X in (A, B)]
+    with jax.named_scope("sparse_norms"):
+        dna2, dnb2 = (out[:, -1] for out in outs)
+    return (outs[0][:, :k].T, outs[1][:, :k].T, dna2, dnb2,
+            outs[0][:, k:k + p] if p else None)
 
 
 # ---------------------------------------------------------------------------

@@ -161,3 +161,58 @@ class SMPPCAResult(NamedTuple):
     summary: SketchSummary
     samples: SampleSet
     sampled_values: jax.Array  # (m,) rescaled-JL estimates on Omega
+
+
+@jax.tree_util.register_pytree_node_class
+class SparseRows:
+    """A chunk of ``t`` rows of a ``(d, n)`` matrix held as its nonzeros.
+
+    ``rows`` (chunk-local row, in ``[0, t)``), ``cols`` and ``vals`` are
+    ``(capacity,)`` arrays; an entry with ``vals == 0`` is padding and adds
+    nothing. The capacity is fixed by the caller, so one compiled update
+    serves every chunk of that capacity. ``shape = (t, n)`` is static (the
+    pytree's aux data), so ``shape[0]`` counts rows as for a dense chunk.
+    A (row, column) pair may appear more than once: its values add.
+
+    >>> import jax.numpy as jnp
+    >>> X = jnp.array([[0., 2., 0.], [1., 0., 0.]])
+    >>> S = SparseRows.from_dense(X, capacity=4)
+    >>> S.shape, S.capacity, bool(jnp.all(S.todense() == X))
+    ((2, 3), 4, True)
+    """
+
+    def __init__(self, rows: jax.Array, cols: jax.Array, vals: jax.Array,
+                 shape):
+        self.rows, self.cols, self.vals = rows, cols, vals
+        self.shape = (int(shape[0]), int(shape[1]))
+
+    def tree_flatten(self):
+        return (self.rows, self.cols, self.vals), self.shape
+
+    @classmethod
+    def tree_unflatten(cls, shape, leaves):
+        return cls(*leaves, shape)
+
+    @property
+    def capacity(self) -> int:
+        """Entries held, padding included."""
+        return self.vals.shape[0]
+
+    def todense(self) -> jax.Array:
+        """The ``(t, n)`` float32 chunk."""
+        return jnp.zeros(self.shape, jnp.float32).at[self.rows, self.cols].add(
+            self.vals.astype(jnp.float32))
+
+    @classmethod
+    def from_dense(cls, X: jax.Array, capacity: int) -> "SparseRows":
+        """The nonzeros of a dense ``(t, n)`` chunk, padded to ``capacity``
+        (which must hold them all: entries past it would be lost)."""
+        nnz = jnp.count_nonzero(X)
+        if not isinstance(nnz, jax.core.Tracer) and int(nnz) > capacity:
+            raise ValueError(f"{int(nnz)} nonzeros do not fit capacity "
+                             f"{capacity}")
+        rows, cols = jnp.nonzero(X, size=capacity, fill_value=0)
+        vals = jnp.where(jnp.arange(capacity) < nnz, X[rows, cols],
+                         0).astype(jnp.float32)
+        return cls(rows.astype(jnp.int32), cols.astype(jnp.int32), vals,
+                   X.shape)

@@ -46,14 +46,25 @@ def _probe_chunk():
         precision=None)
 
 
+def _sparse_contribution():
+    from repro.core.types import SparseRows
+    X = SparseRows(jnp.zeros(64, jnp.int32), jnp.zeros(64, jnp.int32),
+                   jnp.ones(64, F32), (32, 6))
+    return streaming._sparse_contribution.lower(
+        jax.random.PRNGKey(0), probe_omega(jax.random.PRNGKey(0), 6, 3),
+        X, X, jnp.arange(32, dtype=jnp.int32), k=8)
+
+
 @pytest.mark.parametrize("lower,scopes", [
     (_sketch_fused, ["sketch_fused/pallas_call"]),
     (_sampled_dot, ["sampled_dot/pallas_call"]),
     (_blocked_fwht, ["fwht_stage1/pallas_call", "fwht_stage2/pallas_call"]),
     (_chunk_contribution, ["/sketch/", "/norms/"]),
     (_probe_chunk, ["/probe/"]),
+    (_sparse_contribution, ["/sparse_sketch/", "/sparse_norms/",
+                            "/sparse_probe/", "sparse_rows/pallas_call"]),
 ], ids=["sketch_fused", "sampled_dot", "blocked_fwht", "chunk_contribution",
-        "probe_chunk"])
+        "probe_chunk", "sparse_contribution"])
 def test_lowered_program_carries_each_name(lower, scopes):
     text = lower().as_text(debug_info=True)
     for scope in scopes:

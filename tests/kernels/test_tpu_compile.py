@@ -23,7 +23,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import streaming
-from repro.kernels import hadamard, sampled_dot, sketch_fused, tuning
+from repro.kernels import (
+    hadamard, sampled_dot, sketch_fused, sparse_rows, tuning)
 
 K, D_CHUNK, N = 512, 16384, 4096      # the chip smoke's ingest widths
 M = 65536                             # two sampled_dot launches
@@ -134,3 +135,35 @@ def test_chunk_projection_generated_once_for_v5e(one_chip, method):
     assert len(dots) == 2
     for dot in dots:
         assert not any(generation in comps[f] for f in _called(comps, dot))
+
+
+W, T_DOCS, CAP = 102660, 15000, 3604480   # the nytbow.ingest cell's chunk
+
+
+def test_sparse_update_compiles_for_v5e_without_a_gather_of_every_nonzero(
+        one_chip, monkeypatch):
+    """A sparse chunk's sketches, norms and probe summand at the
+    bag-of-words cell's widths: they compile, through the ``sparse_rows``
+    kernel, and their temporaries stay far under one (nonzeros, k) f32
+    gather (7.4 GB)."""
+    from repro.core.types import SparseRows
+    from repro.kernels import ops
+    # the platform here is the CPU, which would take the interpreter
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    streaming._sparse_contribution.clear_cache()
+
+    def update(key, omega, rows, cols, vals, gids):
+        X = SparseRows(rows, cols, vals, (T_DOCS, W))
+        return streaming._sparse_contribution(key, omega, X, X, gids, k=K)
+
+    try:
+        compiled = _compile(
+            update, one_chip, ((2,), jnp.uint32), ((W, 16), jnp.float32),
+            ((CAP,), jnp.int32), ((CAP,), jnp.int32), ((CAP,), jnp.float32),
+            ((T_DOCS,), jnp.int32))
+    finally:    # keep the compiled-for-TPU traces out of later calls
+        streaming._sparse_contribution.clear_cache()
+        sparse_rows.rows_accumulate.clear_cache()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
+    _assert_mosaic(compiled, "sparse_rows")
+    assert f"f32[{CAP},{K}]" not in compiled.as_text()
