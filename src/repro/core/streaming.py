@@ -374,14 +374,34 @@ def _chunk_contribution(key, signs, srows, A_chunk, B_chunk, gids, *,
     return dA, dB, dna2, dnb2
 
 
+def _same_chunk(A_chunk: SparseRows, B_chunk: SparseRows) -> bool:
+    """Whether a sparse pair is one chunk fed as both operands: the same
+    object, or one shape over the very same leaf arrays (``device_put``
+    rebuilds the wrapper around leaves already on the device). Decided
+    from identity alone, since comparing values would read the device: an
+    equal copy counts as another chunk."""
+    return A_chunk is B_chunk or (
+        A_chunk.shape == B_chunk.shape and A_chunk.rows is B_chunk.rows
+        and A_chunk.cols is B_chunk.cols and A_chunk.vals is B_chunk.vals)
+
+
 @functools.partial(jax.jit, static_argnames=("k",))
 def _sparse_contribution(key, omega, A_chunk: SparseRows,
-                         B_chunk: SparseRows, gids, *, k: int):
+                         B_chunk: Optional[SparseRows], gids, *, k: int):
     """(dA, dB, dna2, dnb2, probe delta or None) for one ``SparseRows``
     chunk pair with global row ids ``gids``: the same projection rows as
     the dense path's for those ids, every sum in f32
-    (``summary_engine.sparse_summary_pass``)."""
+    (``summary_engine.sparse_summary_pass``).
+
+    ``B_chunk=None`` (static, as the pytree's structure) means that B is
+    A: one column pass serves both, and dB and dnb2 come back None, for
+    the caller to take dA and dna2 in their place; a program that returned
+    one value twice would copy it into a second buffer."""
     P = projection_rows(key, gids, k)
+    if B_chunk is None:
+        dA, _, dna2, _, dprobe = sparse_summary_pass(P, A_chunk, A_chunk,
+                                                     omega)
+        return dA, None, dna2, None, dprobe
     return sparse_summary_pass(P, A_chunk, B_chunk, omega)
 
 
@@ -625,8 +645,11 @@ class StreamingSummarizer:
                     it = None
                     return
                 with telemetry.span("repro.ingest.stage"):
-                    staged.append((jax.device_put(A_chunk),
-                                   jax.device_put(B_chunk)))
+                    A_dev = jax.device_put(A_chunk)
+                    # a chunk fed as both operands is staged once, so the
+                    # update still sees one chunk (one sparse column pass)
+                    staged.append((A_dev, A_dev if B_chunk is A_chunk
+                                   else jax.device_put(B_chunk)))
 
             for _ in range(prefetch + 1):       # prime the pipeline
                 _stage_next()
@@ -667,8 +690,13 @@ class StreamingSummarizer:
         state = _settle_state(state)
         probe_acc = state.probe_acc
         if sparse:
+            shared = _same_chunk(A_chunk, B_chunk)
             dA, dB, dna2, dnb2, dprobe = _sparse_contribution(
-                state.key, state.omega, A_chunk, B_chunk, gids, k=self.k)
+                state.key, state.omega, A_chunk,
+                None if shared else B_chunk, gids, k=self.k)
+            if shared:
+                telemetry.count("repro.sparse.shared_pass")
+                dB, dnb2 = dA, dna2
             if dprobe is not None:
                 probe_acc = probe_acc + dprobe
         else:

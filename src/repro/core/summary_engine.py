@@ -197,9 +197,11 @@ def sparse_summary_pass(P: jax.Array, A: SparseRows, B: SparseRows,
     ``[P | B omega]`` to its column's row of the output, and its value
     squared to the row's last lane (the norms). ``B omega`` is one more
     pass, over B's entries by row. So the chunk is never densified and no
-    (entries, k) gather exists. Stages: ``sparse_probe`` (``B omega``),
-    ``sparse_sketch`` (the two passes), ``sparse_norms`` (reading the
-    norms' lane)."""
+    (entries, k) gather exists. When ``B is A`` (one chunk fed as both
+    operands, ``A^T A``) the one column pass of A serves both: B's
+    sketch and norms are A's, the very same values. Stages:
+    ``sparse_probe`` (``B omega``), ``sparse_sketch`` (the column passes),
+    ``sparse_norms`` (reading the norms' lane)."""
     from repro.kernels.sparse_rows import rows_accumulate
     t, k = P.shape
     p = 0 if omega is None else omega.shape[1]
@@ -214,10 +216,12 @@ def sparse_summary_pass(P: jax.Array, A: SparseRows, B: SparseRows,
     with jax.named_scope("sparse_sketch"):
         outs = [rows_accumulate(X.vals, X.rows, X.cols, M, n_out=X.shape[1],
                                 squares=True).reshape(X.shape[1], lanes)
-                for X in (A, B)]
+                for X in ((A,) if B is A else (A, B))]
+    sketches = [out[:, :k].T for out in outs]
     with jax.named_scope("sparse_norms"):
-        dna2, dnb2 = (out[:, -1] for out in outs)
-    return (outs[0][:, :k].T, outs[1][:, :k].T, dna2, dnb2,
+        norms = [out[:, -1] for out in outs]
+    # with one pass, [-1] is [0]: B's summands are A's
+    return (sketches[0], sketches[-1], norms[0], norms[-1],
             outs[0][:, k:k + p] if p else None)
 
 

@@ -14,6 +14,10 @@ thread. Occurrences go into one ring of ``CAPACITY`` entries, in the order
 they close; when the ring is full the oldest is overwritten and counted.
 Per-name totals (count, wall ns, CPU ns, longest wall ns) are kept besides.
 
+A named count (``telemetry.count("repro.sparse.shared_pass")``) records how
+often a path was taken where a span would cost too much or say nothing: an
+integer per name, with no annotation and no occurrence in the ring.
+
 The record is always on. An occurrence costs a few microseconds of host
 time, most of it reading the thread's CPU clock, which on some hosts is a
 system call; with no profiler running the annotation costs under one.
@@ -22,8 +26,8 @@ single occurrence's CPU time is 0 or a whole tick, and only sums over
 many occurrences are meaningful. Span names start with ``repro.``.
 
 Read side, as plain values: ``snapshot()`` gives the per-name totals,
-``occurrences()`` the ring oldest first, and ``overwritten()`` how many
-occurrences the ring lost.
+``occurrences()`` the ring oldest first, ``overwritten()`` how many
+occurrences the ring lost, and ``counts()`` the named counts.
 """
 from __future__ import annotations
 
@@ -102,10 +106,21 @@ class Recorder:
         self._ring: List[Optional[tuple]] = [None] * capacity
         self._written = 0
         self._totals: Dict[str, List[int]] = {}
+        self._counts: Dict[str, int] = {}
 
     def span(self, name: str) -> _Span:
         """A context manager: a profiler annotation and one occurrence."""
         return _Span(self, name)
+
+    def count(self, name: str) -> None:
+        """Add one to the named count."""
+        with self._lock:
+            self._counts[name] = self._counts.get(name, 0) + 1
+
+    def counts(self) -> Dict[str, int]:
+        """Every named count since the record began."""
+        with self._lock:
+            return dict(self._counts)
 
     def _stack(self) -> list:
         stack = getattr(self._local, "stack", None)
@@ -161,3 +176,5 @@ span = RECORDER.span
 snapshot = RECORDER.snapshot
 occurrences = RECORDER.occurrences
 overwritten = RECORDER.overwritten
+count = RECORDER.count
+counts = RECORDER.counts

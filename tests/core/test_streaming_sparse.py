@@ -9,13 +9,16 @@ partial sums exist for). Tolerance: both paths sum f32 products in f32, in
 different orders (the dense path in MXU/BLAS blocks, the sparse path by
 column in the kernel's partial sums), so entries agree to a few f32
 roundings of their largest terms: 2e-5 relative to each block's largest
-entry, against ~1e-3 for the projection cut to bf16.
+entry, against ~1e-3 for the projection cut to bf16. One chunk fed as both
+operands takes one column pass (``repro.sparse.shared_pass``), whose
+summary equals the two-pass summary of an equal copy bit for bit.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import telemetry
 from repro.core import StreamingSummarizer, WindowedSummarizer, merge_states
 from repro.core.estimation_engine import estimate_product
 from repro.core.types import SparseRows
@@ -76,6 +79,75 @@ def test_sparse_chunks_match_the_dense_path(key, probes, entry):
     assert int(sparse.rows_seen) == 2 * T
     assert int(sparse.row_high) == int(dense.row_high) == 3 * T
     _close(summ.finalize(sparse), summ.finalize(dense))
+
+
+SHARED = "repro.sparse.shared_pass"
+STATE = ("A_acc", "B_acc", "na2", "nb2", "probe_acc")
+
+
+def _shared_passes():
+    return telemetry.counts().get(SHARED, 0)
+
+
+def _copy(S):
+    return SparseRows(*(jnp.array(leaf, copy=True)
+                        for leaf in (S.rows, S.cols, S.vals)), S.shape)
+
+
+@pytest.mark.parametrize("feed,passes", [
+    ("same object", 1), ("same leaves", 1), ("equal copy", 0),
+    ("dense", 0)])
+def test_one_chunk_as_both_operands_takes_one_column_pass(key, feed,
+                                                          passes):
+    """The one-pass summary equals the two-pass summary of an equal copy
+    bit for bit; the count reads one per chunk that took the one pass."""
+    summ = StreamingSummarizer(k=K, probes=P)
+    X = _chunk(12)
+    S = _sparse(X)
+    init = summ.init(key, (T, N, N))
+    two_pass = summ.update(init, S, _copy(S), 0)
+    A, B = {"same object": (S, S),
+            "same leaves": (S, SparseRows(S.rows, S.cols, S.vals, S.shape)),
+            "equal copy": (S, _copy(S)), "dense": (X, X)}[feed]
+    before = _shared_passes()
+    state = summ.update(init, A, B, 0)
+    assert _shared_passes() - before == passes
+    if feed == "dense":
+        _close(summ.finalize(state), summ.finalize(two_pass))
+        return
+    for name in STATE:
+        assert bool(jnp.all(getattr(state, name)
+                            == getattr(two_pass, name))), name
+
+
+def test_ingest_stages_a_host_chunk_fed_as_both_operands_once(key,
+                                                              monkeypatch):
+    summ = StreamingSummarizer(k=K, probes=P)
+    chunks = [SparseRows(*(np.asarray(leaf) for leaf in
+                           (S.rows, S.cols, S.vals)), S.shape)
+              for S in (_sparse(_chunk(s)) for s in (13, 14))]
+    handed = []
+    update = summ.update
+
+    def spy(state, A, B, off):
+        handed.append((A, B))
+        return update(state, A, B, off)
+
+    monkeypatch.setattr(summ, "update", spy)
+    init = summ.init(key, (2 * T, N, N))
+    before = _shared_passes()
+    state = summ.ingest(init, [(S, S) for S in chunks], row_offset=0)
+    assert _shared_passes() - before == len(chunks)
+    assert len(handed) == len(chunks)
+    for A, B in handed:
+        assert A is B and isinstance(A.vals, jax.Array)
+    want = init
+    for i, S in enumerate(chunks):
+        D = jax.device_put(S)
+        want = update(want, D, D, i * T)
+    for name in STATE:
+        assert bool(jnp.all(getattr(state, name) == getattr(want, name))), \
+            name
 
 
 def test_a_chunk_of_padding_adds_exactly_nothing(key):

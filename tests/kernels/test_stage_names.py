@@ -46,13 +46,17 @@ def _probe_chunk():
         precision=None)
 
 
-def _sparse_contribution():
+def _sparse_contribution(shared):
     from repro.core.types import SparseRows
     X = SparseRows(jnp.zeros(64, jnp.int32), jnp.zeros(64, jnp.int32),
                    jnp.ones(64, F32), (32, 6))
-    return streaming._sparse_contribution.lower(
+    return lambda: streaming._sparse_contribution.lower(
         jax.random.PRNGKey(0), probe_omega(jax.random.PRNGKey(0), 6, 3),
-        X, X, jnp.arange(32, dtype=jnp.int32), k=8)
+        X, None if shared else X, jnp.arange(32, dtype=jnp.int32), k=8)
+
+
+SPARSE_SCOPES = ["/sparse_sketch/", "/sparse_norms/", "/sparse_probe/",
+                 "sparse_rows/pallas_call"]
 
 
 @pytest.mark.parametrize("lower,scopes", [
@@ -61,10 +65,10 @@ def _sparse_contribution():
     (_blocked_fwht, ["fwht_stage1/pallas_call", "fwht_stage2/pallas_call"]),
     (_chunk_contribution, ["/sketch/", "/norms/"]),
     (_probe_chunk, ["/probe/"]),
-    (_sparse_contribution, ["/sparse_sketch/", "/sparse_norms/",
-                            "/sparse_probe/", "sparse_rows/pallas_call"]),
+    (_sparse_contribution(shared=False), SPARSE_SCOPES),
+    (_sparse_contribution(shared=True), SPARSE_SCOPES),
 ], ids=["sketch_fused", "sampled_dot", "blocked_fwht", "chunk_contribution",
-        "probe_chunk", "sparse_contribution"])
+        "probe_chunk", "sparse_contribution", "sparse_contribution_shared"])
 def test_lowered_program_carries_each_name(lower, scopes):
     text = lower().as_text(debug_info=True)
     for scope in scopes:

@@ -140,30 +140,45 @@ def test_chunk_projection_generated_once_for_v5e(one_chip, method):
 W, T_DOCS, CAP = 102660, 15000, 3604480   # the nytbow.ingest cell's chunk
 
 
+@pytest.mark.parametrize("shared", [True, False],
+                         ids=["shared", "distinct"])
 def test_sparse_update_compiles_for_v5e_without_a_gather_of_every_nonzero(
-        one_chip, monkeypatch):
+        one_chip, monkeypatch, shared):
     """A sparse chunk's sketches, norms and probe summand at the
     bag-of-words cell's widths: they compile, through the ``sparse_rows``
     kernel, and their temporaries stay far under one (nonzeros, k) f32
-    gather (7.4 GB)."""
+    gather (7.4 GB). One chunk fed as both operands compiles to one column
+    sort and two kernel passes (``B omega`` by row, A by column); a
+    distinct pair to two column sorts and three passes. (The row pass
+    holds a sort too, which it skips at run time: the entries come in row
+    order.)"""
     from repro.core.types import SparseRows
     from repro.kernels import ops
     # the platform here is the CPU, which would take the interpreter
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     streaming._sparse_contribution.clear_cache()
 
-    def update(key, omega, rows, cols, vals, gids):
-        X = SparseRows(rows, cols, vals, (T_DOCS, W))
-        return streaming._sparse_contribution(key, omega, X, X, gids, k=K)
+    def update(key, omega, gids, *leaves):
+        A, B = (SparseRows(*leaves[i:i + 3], (T_DOCS, W))
+                for i in (0, len(leaves) - 3))
+        return streaming._sparse_contribution(
+            key, omega, A, None if shared else B, gids, k=K)
 
+    chunk = (((CAP,), jnp.int32), ((CAP,), jnp.int32), ((CAP,), jnp.float32))
     try:
         compiled = _compile(
             update, one_chip, ((2,), jnp.uint32), ((W, 16), jnp.float32),
-            ((CAP,), jnp.int32), ((CAP,), jnp.int32), ((CAP,), jnp.float32),
-            ((T_DOCS,), jnp.int32))
+            ((T_DOCS,), jnp.int32), *chunk * (1 if shared else 2))
     finally:    # keep the compiled-for-TPU traces out of later calls
         streaming._sparse_contribution.clear_cache()
         sparse_rows.rows_accumulate.clear_cache()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
     _assert_mosaic(compiled, "sparse_rows")
-    assert f"f32[{CAP},{K}]" not in compiled.as_text()
+    text = compiled.as_text()
+    assert f"f32[{CAP},{K}]" not in text
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        (2 if shared else 3)
+    entry_sorts = [line for line in text.splitlines()
+                   if " sort(" in line and f"[{CAP}]" in line]
+    assert sum("/sparse_sketch/" in line for line in entry_sorts) == \
+        (1 if shared else 2)

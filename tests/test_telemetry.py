@@ -135,6 +135,17 @@ def test_two_threads_keep_their_own_parents():
         "repro.t.b": rounds, "repro.t.b.child": rounds}
 
 
+def test_named_counts_add_up_apart_from_the_spans():
+    rec = telemetry.Recorder(capacity=4)
+    for name in ("repro.t.hits", "repro.t.other", "repro.t.hits"):
+        rec.count(name)
+    assert rec.counts() == {"repro.t.hits": 2, "repro.t.other": 1}
+    assert rec.snapshot() == {} and rec.occurrences() == []
+    before = telemetry.counts().get("repro.t.module", 0)
+    telemetry.count("repro.t.module")
+    assert telemetry.counts()["repro.t.module"] == before + 1
+
+
 def test_module_functions_read_the_process_record():
     before = telemetry.snapshot().get("repro.t.module", {"count": 0})
     with telemetry.span("repro.t.module"):
