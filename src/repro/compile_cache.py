@@ -1,5 +1,5 @@
 """Where JAX's persistent compilation cache lives for this program's
-entry points (``chip_smoke.py``, ``benchmarks/run.py``,
+entry points (``chip_smoke.py``, ``bench/run.py``,
 ``python -m repro.launch.serve``).
 
 A cached executable is keyed partly on the cache's path, so the path has to

@@ -192,8 +192,7 @@ def waltmin_reference(key: jax.Array, samples: SampleSet, values: jax.Array,
                       use_splits: bool = True) -> LowRankFactors:
     """Algorithm 2 as written on the page: T Python-level iteration pairs,
     every half-step dispatched eagerly (no jit, no scan) — the
-    EstimationEngine's ``backend='reference'`` oracle, and the baseline the
-    jitted scan loop's speedup is measured against (benchmarks/run.py
-    ``--suite estimation``)."""
+    EstimationEngine's ``backend='reference'`` oracle for the jitted scan
+    loop."""
     return _waltmin_impl(key, samples, values, n1, n2, r, T, norm_A,
                          use_splits, scan=False)

@@ -675,15 +675,6 @@ def retune(shapes: Dict[str, List[Tuple[int, ...]]], *, backend: str,
     return table
 
 
-def achieved_gbps(cfg: KernelConfig, shape: Tuple[int, ...],
-                  us_per_call: float, *, dtype_bytes: int = 4) -> float:
-    """Modeled HBM bytes over measured wall time — the bench suite's
-    bandwidth metric (meaningful on real hardware; on interpret-mode CPU
-    it is a relative figure only)."""
-    cost = roofline_cost(cfg, shape, dtype_bytes=dtype_bytes)
-    return cost.hbm_bytes / (us_per_call * 1e-6) / 1e9
-
-
 def _occupancy_note() -> str:   # pragma: no cover - doc helper
     return (f"MXU occupancy derates {PEAK_FLOPS / 1e12:.0f} TFLOP/s peak; "
             f"HBM terms assume {HBM_BW / 1e9:.0f} GB/s")

@@ -151,7 +151,7 @@ class LoopConfig(NamedTuple):
 
 @dataclasses.dataclass
 class LoopStats:
-    """Observable serving counters (the traffic benchmark's raw cells)."""
+    """Observable serving counters."""
 
     admitted: int = 0             # requests accepted into the queue
     completed: int = 0            # requests resolved with a result

@@ -6,7 +6,8 @@ dY (T x n_out), T = tokens — exactly the paper's A^T B with the huge streamed
 dimension d = T. Stable ranks of activations/cotangents are far below T, so
 the paper's bounds bite at small sketch k (unlike the A=I mapping used by the
 grads-level baseline in optim.grad_compression, whose A has stable rank n_in
-— that contrast is benchmarked in benchmarks/grad_compression.py).
+— tests/train/test_training.py::test_tap_gradient_beats_identity_baseline
+holds that contrast).
 
 Mechanics (jit/pjit-pure, no side channels):
   * the layer's params carry zero-initialized *tap* leaves

@@ -1,6 +1,9 @@
 """Shared pytest fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches
 must see the single real CPU device; multi-device tests spawn subprocesses
 with their own flags (tests/dist/)."""
+import math
+import zlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -30,6 +33,27 @@ def planted_pair(key, d, n, decay=1.0, corr=None):
     else:
         B = A + corr * jax.random.normal(kB, (d, n)) @ D
     return A, B
+
+
+def figure_key(name):
+    """The key of one paper-figure check: ``PRNGKey(0)`` folded by the crc32
+    of the figure's name, so each figure draws its own data."""
+    return jax.random.fold_in(jax.random.PRNGKey(0),
+                              zlib.crc32(name.encode()) % 2**31)
+
+
+def cone_pair(key, d, n, theta):
+    """Unit columns drawn from a cone of angle theta around one direction,
+    with random signs: A's columns and B's columns are the two halves (the
+    paper's Fig 2(b) construction; narrower cones are more aligned)."""
+    kx, kt, ks = jax.random.split(key, 3)
+    x = jax.random.normal(kx, (d, 1))
+    x = x / jnp.linalg.norm(x)
+    t = jax.random.normal(kt, (d, 2 * n)) * (math.tan(theta / 2) / math.sqrt(d))
+    sign = jnp.where(jax.random.bernoulli(ks, 0.5, (2 * n,)), 1.0, -1.0)
+    y = (x + t) * sign[None, :]
+    y = y / jnp.linalg.norm(y, axis=0)
+    return y[:, :n], y[:, n:]
 
 
 def gaussian_pair(key, d=192, n1=11, n2=7):

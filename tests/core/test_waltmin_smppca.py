@@ -1,7 +1,11 @@
 """Step-3 + end-to-end tests: WAltMin completion and Algorithm 1."""
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 try:
     from hypothesis import given, settings, strategies as st
 except ImportError:          # hypothesis is optional; see tests/_hyp.py
@@ -10,7 +14,7 @@ except ImportError:          # hypothesis is optional; see tests/_hyp.py
 from repro import core
 from repro.core.types import SampleSet
 from repro.core.waltmin import waltmin
-from tests.conftest import planted_pair
+from tests.conftest import cone_pair, figure_key, planted_pair
 
 
 def _full_sample(n1, n2):
@@ -69,7 +73,7 @@ def test_coo_topr_svd_matches_dense(key):
 
 
 # ---------------------------------------------------------------------------
-# End-to-end SMP-PCA (paper-claim regressions live in benchmarks too)
+# End-to-end SMP-PCA and the paper's figure claims (docs/paper_map.md)
 # ---------------------------------------------------------------------------
 
 def _m(n, r):
@@ -147,6 +151,46 @@ def test_product_of_pcas_fails_on_orthogonal_subspaces(key):
     e_smp, _ = core.spectral_error_vs_optimal(A, B, r, res.factors)
     assert float(e_pp) > 0.5
     assert float(e_smp) < float(e_pp)
+
+
+@functools.lru_cache(maxsize=None)
+def _fig4a_lela_error(mult):
+    """LELA's spectral error on the Fig 4(a) pair with m = mult * n r log n."""
+    key = figure_key("fig4a_phase")
+    d, n, r = 1000, 400, 3
+    kU, kV = jax.random.split(key)
+    A = jax.random.normal(kU, (d, n))
+    B = (A @ jax.random.normal(kV, (n, r)) @ jax.random.normal(
+        jax.random.fold_in(kV, 1), (r, n)) / n
+         + 0.01 * jax.random.normal(jax.random.fold_in(kV, 2), (d, n)))
+    m = int(mult * n * r * math.log(n))
+    f = core.lela(key, A, B, r=r, m=m, T=8)
+    return float(core.spectral_error(A, B, f))
+
+
+@pytest.mark.parametrize("mult", [1.0, 4.0])
+def test_fig4a_lela_phase_transition_at_n_r_log_n(mult):
+    """Paper Fig 4(a): LELA's error drops sharply once m reaches
+    Theta(n r log n): at half that it is at least ten times the error at
+    mult * n r log n (CPU: 1.68 against 0.041 and 0.015)."""
+    assert _fig4a_lela_error(0.5) >= 10 * _fig4a_lela_error(mult), (
+        _fig4a_lela_error(0.5), _fig4a_lela_error(mult))
+
+
+@pytest.mark.parametrize("theta", [0.2, 1.0])
+def test_fig4b_smppca_beats_sketch_svd_on_cone(theta):
+    """Paper Fig 4(b): through sampling and WAltMin, SMP-PCA's error on a
+    cone pair is below SVD(A~^T B~)'s at the same k (CPU: ratios 45 and
+    4.9)."""
+    key = figure_key("fig4b_cone_full")
+    d, n, r, k = 1000, 150, 3, 64
+    m = int(10 * n * r * math.log(n))
+    A, B = cone_pair(jax.random.fold_in(key, int(theta * 10)), d, n, theta)
+    res = core.smppca(key, A, B, r=r, k=k, m=m, T=6)
+    e_smp = float(core.spectral_error(A, B, res.factors))
+    sf = core.sketch_svd(key, A, B, r=r, k=k)
+    e_svd = float(core.spectral_error(A, B, sf))
+    assert e_smp < e_svd, (e_smp, e_svd)
 
 
 def test_smppca_streaming_summary_entry_point(key):

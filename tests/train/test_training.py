@@ -14,6 +14,7 @@ from repro.optim import AdamW, warmup_cosine
 from repro.optim import grad_compression as gc
 from repro.train import TrainConfig, Trainer, TrainerConfig
 from repro.train import sketched_dense as sd
+from tests.conftest import figure_key
 
 
 # ---------------------------------------------------------------------------
@@ -209,6 +210,41 @@ def test_sketched_dense_taps_ride_grads():
     dx_ref = jax.grad(lambda x: jnp.mean((x @ w) ** 2))(x)
     np.testing.assert_allclose(np.asarray(dx), np.asarray(dx_ref),
                                rtol=1e-4, atol=1e-5)
+
+
+def _cosine(a, b):
+    return float(jnp.sum(a * b) / (jnp.linalg.norm(a) * jnp.linalg.norm(b)))
+
+
+def test_tap_gradient_beats_identity_baseline():
+    """The tap path (sketches of X and dY, their exact column norms, then
+    SMP-PCA) reconstructs a dense layer's gradient closer to the true one
+    than compressing the gradient itself with A = I, at the same rank and
+    sketch width (CPU: cosines 0.94 against 0.56)."""
+    key = figure_key("grad_compression")
+    n_in, n_out, T = 256, 1024, 8192
+    kw, kx, kz, kp1, kp2 = jax.random.split(key, 5)
+    w_true = jax.random.normal(kw, (n_in, n_out)) * 0.05
+    pert = (jax.random.normal(kp1, (n_in, 6)) @
+            jax.random.normal(kp2, (6, n_out))) * 0.02
+    w = w_true + pert
+    z = jax.random.normal(kz, (8, T // 8, 16))
+    E = jax.random.normal(jax.random.fold_in(kx, 1), (16, n_in))
+    x = z @ E + 0.05 * jax.random.normal(kx, (8, T // 8, n_in))
+    target = x @ w_true
+
+    def loss(w, taps, x):
+        return jnp.mean((sd.sketched_dense(w, taps, x, key, 128, 1024)
+                         - target) ** 2)
+
+    _, dtaps, _ = jax.grad(loss, argnums=(0, 1, 2))(
+        w, sd.tap_init(n_in, n_out, 128), x)
+    g_taps = sd.decompress_tap(key, dtaps, sd.TapConfig(sketch_k=128, rank=8))
+    dw_true = jax.grad(lambda w: jnp.mean((x @ w - target) ** 2))(w)
+    g_eye = gc.compress_leaf(key, dw_true,
+                             gc.CompressionConfig(rank=8, sketch_k=128))
+    cos_taps, cos_eye = _cosine(dw_true, g_taps), _cosine(dw_true, g_eye)
+    assert cos_taps > cos_eye, (cos_taps, cos_eye)
 
 
 def test_decompress_tapped_grads_walks_stacked_layers():
