@@ -11,7 +11,12 @@ one row update in VMEM, and no (entries, m) block ever exists. Each output
 row is held as ``PARTS`` partial sums, entry ``j`` adding to partial
 ``j % PARTS``, summed when the tile is done: a row that takes 15,000
 entries (a frequent word) sums chains of ~1,900 f32 terms, not one of
-15,000.
+15,000. The entries are walked in aligned groups of ``PARTS``, whose
+entries add to distinct partials: a group loads all its rows of ``M`` and
+of the partials before it stores any, so an entry does not wait on the
+previous entry's store, and each partial still takes its entries in order
+with the same arithmetic (the output is bit for bit that of one entry at a
+time). An item's unaligned head and tail go one entry at a time.
 
 The entries are ordered by ``dst`` first (one XLA sort, skipped when they
 already are). The grid runs over *items*, the pieces into which the tile
@@ -68,19 +73,45 @@ def _kernel(slab_ref, tile_ref, lo_ref, hi_ref, first_ref, last_ref,
                 & (jax.lax.broadcasted_iota(jnp.int32, shape, 1)
                    == shape[1] - 1)).astype(jnp.float32)
 
-    def body(j, carry):
-        r, d, v = src_ref[j], dst_ref[j] - base, val_ref[j]
-        upd = v * (m_vmem[r] if three_d else m_vmem[pl.ds(r, 1), :])
+    def row(r):
+        return m_vmem[r] if three_d else m_vmem[pl.ds(r, 1), :]
+
+    def at(part, d):
+        return (part, d) if three_d else (part, pl.ds(d, 1), slice(None))
+
+    def update(v, m_row, acc_row):
+        upd = v * m_row
         if squares:
             upd = upd + (v * v) * last
-        part = j % PARTS
-        if three_d:
-            acc_ref[part, d] += upd
-        else:
-            acc_ref[part, pl.ds(d, 1), :] += upd
+        return acc_row + upd
+
+    def one(j, carry):
+        r, d, v = src_ref[j], dst_ref[j] - base, val_ref[j]
+        ix = at(j % PARTS, d)
+        acc_ref[ix] = update(v, row(r), acc_ref[ix])
         return carry
 
-    jax.lax.fori_loop(lo_ref[i], hi_ref[i], body, 0)
+    def group(g, carry):
+        # entries g*PARTS + u add to partials u: distinct rows of acc, so
+        # every load may come before every store
+        js = [g * PARTS + u for u in range(PARTS)]
+        rs = [src_ref[j] for j in js]
+        ixs = [at(u, dst_ref[j] - base) for u, j in enumerate(js)]
+        vs = [val_ref[j] for j in js]
+        m_rows = [row(r) for r in rs]
+        acc_rows = [acc_ref[ix] for ix in ixs]
+        new = [update(*t) for t in zip(vs, m_rows, acc_rows)]
+        for ix, a in zip(ixs, new):
+            acc_ref[ix] = a
+        return carry
+
+    # whole groups between an unaligned head and tail of single entries
+    lo, hi = lo_ref[i], hi_ref[i]
+    g_lo = (lo + PARTS - 1) // PARTS
+    g_hi = jnp.maximum(g_lo, hi // PARTS)
+    jax.lax.fori_loop(lo, jnp.minimum(g_lo * PARTS, hi), one, 0)
+    jax.lax.fori_loop(g_lo, g_hi, group, 0)
+    jax.lax.fori_loop(g_hi * PARTS, hi, one, 0)
 
     @pl.when(last_ref[i] == 1)
     def _():
